@@ -18,8 +18,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.compat import axis_size
-
 BLOCK = 1024
 
 
@@ -45,7 +43,7 @@ def compressed_psum_scatter_gather(x: jax.Array, axis: str,
 
     Returns (reduced [n], new_err). n must divide (devices * BLOCK).
     """
-    nd = axis_size(axis)
+    nd = jax.lax.axis_size(axis)
     # 1) bf16 reduce_scatter: each device owns n/nd reduced elements
     shard = jax.lax.psum_scatter(x.astype(jnp.bfloat16), axis,
                                  scatter_dimension=0, tiled=True)

@@ -15,7 +15,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from repro.distributed.compat import axis_size, shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
@@ -67,7 +66,7 @@ def make_flash_decode(mesh: Mesh):
             sc_loc = kc.shape[1]
             if seq_ok:
                 midx = jax.lax.axis_index("model")
-                nshard = axis_size("model")
+                nshard = jax.lax.axis_size("model")
             else:
                 midx, nshard = 0, 1
             if write:
@@ -105,7 +104,7 @@ def make_flash_decode(mesh: Mesh):
         kv_spec = P(bspec, sspec, None, None)
         kp_spec = P(bspec, sspec)
         new_specs = (P(bspec, None, None),) * 2 if write else ()
-        fn = shard_map(
+        fn = jax.shard_map(
             inner, mesh=mesh,
             in_specs=(kv_spec, kv_spec, kp_spec, P(bspec, None, None), P())
             + new_specs,

@@ -25,7 +25,6 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from repro.distributed.compat import axis_size, shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
@@ -139,7 +138,7 @@ def make_moe_etp(mesh: Mesh):
             aux = jax.lax.pmean(aux, all_axes)
             return y.reshape(b_loc, s_loc, d), aux
 
-        fn = shard_map(
+        fn = jax.shard_map(
             inner_fn, mesh=mesh,
             in_specs=(P(batch, "model", None), P(None, None),
                       P("model", None, None, None),
@@ -222,7 +221,7 @@ def make_moe_etp2d(mesh: Mesh):
 
         w_spec = P("model", baxes if len(baxes) > 1 else baxes[0],
                    None, None, None)
-        fn = shard_map(
+        fn = jax.shard_map(
             inner_fn, mesh=mesh,
             in_specs=(P(baxes if len(baxes) > 1 else baxes[0], "model",
                         None),
@@ -290,7 +289,7 @@ def make_moe_replicated(mesh: Mesh, expert_2d: bool = False):
                 idx = jnp.int32(0)
                 stride = b_tot
                 for ax in baxes:
-                    stride = stride // axis_size(ax)
+                    stride = stride // jax.lax.axis_size(ax)
                     idx = idx + jax.lax.axis_index(ax) * stride
                 y_tok = jax.lax.dynamic_slice_in_dim(
                     y_tok.reshape(b_tot, s, d), idx, b_loc, axis=0)
@@ -305,7 +304,7 @@ def make_moe_replicated(mesh: Mesh, expert_2d: bool = False):
             P("model", None, None, None)
         wo_spec = P("model", None, batch, None) if use_2d else \
             P("model", None, None, None)
-        fn = shard_map(
+        fn = jax.shard_map(
             inner_fn, mesh=mesh,
             in_specs=(P(batch, None, None), P(None, None),
                       w_spec, w_spec, wo_spec),

@@ -16,7 +16,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from repro.distributed.compat import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
@@ -69,7 +68,7 @@ def pipeline_apply(mesh: Mesh, stage_fn: Callable, stage_params,
 
     in_specs = (jax.tree.map(lambda _: P(axis), stage_params,
                              is_leaf=lambda x: False), P())
-    fn = shard_map(
+    fn = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(axis), stage_params), P()),
         out_specs=P(),

@@ -31,7 +31,10 @@ def _rglru_kernel(loga_ref, gated_ref, o_ref, h_scr, *, bs: int, bw: int):
     log_a = loga_ref[0].astype(jnp.float32)          # [bs, bw]
     gated = gated_ref[0].astype(jnp.float32)
     a = jnp.exp(log_a)
-    b = jnp.sqrt(jnp.maximum(-jnp.expm1(2.0 * log_a), 1e-12)) * gated
+    # 1 - a^2 == -expm1(2 log_a) == -tanh(log_a) (1 + a^2): exact near
+    # a -> 1 without expm1, which has no TPU lowering
+    b = jnp.sqrt(jnp.maximum(-jnp.tanh(log_a) * (1.0 + a * a), 1e-12)) \
+        * gated
 
     # In-block inclusive scan (Blelloch-style doubling on dense arrays):
     # after k rounds, (A[t], B[t]) compose the last 2^k steps ending at t.

@@ -221,8 +221,6 @@ def build_and_compile(arch: str, shape_name: str, multi_pod: bool,
     t_compile = time.time() - t0
 
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # JAX 0.4.x: one dict per device
-        cost = cost[0] if cost else {}
     mem = compiled.memory_analysis()
     hlo = compiled.as_text()
     if os.environ.get("DRYRUN_SAVE_HLO"):

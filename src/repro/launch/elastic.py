@@ -6,31 +6,16 @@ from __future__ import annotations
 
 import argparse
 import tempfile
-import time
 from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import ParallelConfig, ShapeConfig, registry
+from repro.configs import ShapeConfig, registry
 from repro.core.cluster import SimCluster
 from repro.data.pipeline import StagedDataset
-from repro.distributed import sharding as shd
-from repro.launch.mesh import make_mesh
-from repro.models import transformer as tfm
-from repro.train import optimizer as opt
-from repro.train import train_step as ts
-
-
-def _build(cfg, shape, lr):
-    mesh = make_mesh((1, 1), ("data", "model"))
-    plan = shd.Plan(mesh, cfg, shape, ParallelConfig())
-    rt = plan.runtime()
-    adamw = opt.AdamWConfig(lr=lr, warmup=10)
-    step_fn = jax.jit(ts.make_train_step(cfg, rt, plan.constrain, adamw,
-                                         ce_chunk=128))
-    return rt, adamw, step_fn
+from repro.launch.train import build_trainer
 
 
 def main(argv=None):
@@ -44,9 +29,8 @@ def main(argv=None):
 
     cfg = registry.get_smoke_config(args.arch)
     shape = ShapeConfig("cli", 32, 4, "train")
-    rt, adamw, step_fn = _build(cfg, shape, 1e-3)
-    params, _ = tfm.init_params(jax.random.PRNGKey(0), cfg, rt)
-    opt_state = opt.init_opt_state(params, adamw)
+    tr = build_trainer(cfg, shape, devices=jax.devices()[:1], lr=1e-3)
+    step_fn, params, opt_state = tr.step_fn, tr.params, tr.opt_state
 
     root = Path(args.root or tempfile.mkdtemp())
     c1 = SimCluster(root / "phase1", n_nodes=args.nodes_before)

@@ -1,38 +1,22 @@
 """Production mesh builders (assignment-mandated shapes).
 
 Functions, not module-level constants, so importing never touches jax
-device state.
-
-``jax.sharding.AxisType`` only exists on newer JAX releases (>= 0.5);
-on 0.4.x meshes every axis is implicitly "auto", so the kwarg must be
-omitted entirely. ``_mesh_axis_kwargs`` centralises that version probe so
-every mesh in the repo builds on both API variants.
+device state. Every axis is ``AxisType.Auto``: shardings are propagated
+by GSPMD from the arguments' and constraints' specs.
 """
 from __future__ import annotations
 
 import jax
 
 
-def _mesh_axis_kwargs(n_axes: int, sharding_mod=None) -> dict:
-    """kwargs for ``jax.make_mesh`` marking all ``n_axes`` axes as Auto.
-
-    Returns ``{}`` when the installed JAX predates
-    ``jax.sharding.AxisType`` (e.g. 0.4.x), where Auto is the implicit
-    default. ``sharding_mod`` is injectable for compat tests.
-    """
-    sharding = sharding_mod if sharding_mod is not None else jax.sharding
-    if hasattr(sharding, "AxisType"):
-        return {"axis_types": (sharding.AxisType.Auto,) * n_axes}
-    return {}
+def make_mesh(shape, axes, devices=None):
+    """A mesh over ``devices`` (default: all local devices) with all
+    axes Auto."""
+    return jax.make_mesh(tuple(shape), tuple(axes), devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_mesh_axis_kwargs(len(axes)))
-
-
-def make_mesh(shape, axes):
-    """Arbitrary (test-scale) mesh with the same axis conventions."""
-    return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_mesh_axis_kwargs(len(axes)))
+    return make_mesh(shape, axes)

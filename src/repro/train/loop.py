@@ -41,6 +41,9 @@ class LoopConfig:
 class LoopState:
     step: int = 0
     losses: List[float] = field(default_factory=list)
+    # host seconds per step, up to the loss on the host (so the device
+    # finished the step)
+    step_seconds: List[float] = field(default_factory=list)
     ckpt_seconds: List[float] = field(default_factory=list)
     recovered_at: List[int] = field(default_factory=list)
     # acknowledged durability of the final checkpoint at shutdown
@@ -54,8 +57,13 @@ def run(train_step_fn: Callable, params, opt_state,
         loop_cfg: LoopConfig,
         fault_at: Optional[int] = None) -> LoopState:
     """Drive training with checkpoint/restart. ``fault_at`` kills a node
-    after that step (test/demo hook) to exercise recovery."""
+    after that step (test/demo hook) to exercise recovery.
+
+    ``train_step_fn`` may donate its params and opt_state: the loop reads
+    only the state a step returned, and a restore lands on the layout the
+    run started on (the shardings are taken before the first step)."""
     state = LoopState()
+    layout = jax.tree.map(lambda x: x.sharding, (params, opt_state))
     sd = StragglerDetector()
     last_full = None
     last_ticket = None
@@ -71,6 +79,7 @@ def run(train_step_fn: Callable, params, opt_state,
             state.losses.append(loss)
             state.step = step + 1
             dt = time.time() - t0
+            state.step_seconds.append(dt)
             for nid in cluster.node_ids:
                 if nid in dead_nodes:
                     continue  # a forgotten victim must STAY forgotten:
@@ -123,10 +132,8 @@ def run(train_step_fn: Callable, params, opt_state,
                 if daemon is None or \
                         not daemon.wait_for([victim], timeout=60.0):
                     cluster.tiered.repair([victim])
-                params = jax.tree.map(jax.numpy.asarray,
-                                      restored["params"])
-                opt_state = jax.tree.map(jax.numpy.asarray,
-                                         restored["opt"])
+                params, opt_state = jax.device_put(
+                    (restored["params"], restored["opt"]), layout)
                 state.recovered_at.append(step + 1)
                 fault_at = None
     finally:

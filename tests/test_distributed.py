@@ -121,7 +121,6 @@ def test_moe_decode_2d_experts(n_experts):
 def test_compressed_psum():
     _run("""
     import jax, jax.numpy as jnp, numpy as np
-    from repro.distributed.compat import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.launch.mesh import make_mesh
     from repro.distributed.compression import (
@@ -133,18 +132,19 @@ def test_compressed_psum():
     def f(xl, el):
         y, e = compressed_psum_scatter_gather(xl[0], "data", el[0])
         return y[None], e[None]
-    y, e = shard_map(f, mesh=mesh, in_specs=(P("data"), P("data")),
-                     out_specs=(P("data"), P("data")),
-                     check_vma=False)(x, err0)
+    y, e = jax.shard_map(f, mesh=mesh, in_specs=(P("data"), P("data")),
+                         out_specs=(P("data"), P("data")),
+                         check_vma=False)(x, err0)
     ref = x.mean(0)
     rel = float(jnp.abs(y[0] - ref).max() / jnp.abs(ref).max())
     assert rel < 0.02, rel  # int8 broadcast error ~1/127
     # error feedback: repeated reductions stay unbiased
     acc = jnp.zeros_like(ref); eacc = err0
     for i in range(8):
-        y, eacc = shard_map(f, mesh=mesh, in_specs=(P("data"), P("data")),
-                            out_specs=(P("data"), P("data")),
-                            check_vma=False)(x, eacc)
+        y, eacc = jax.shard_map(f, mesh=mesh,
+                                in_specs=(P("data"), P("data")),
+                                out_specs=(P("data"), P("data")),
+                                check_vma=False)(x, eacc)
         acc = acc + y[0]
     rel = float(jnp.abs(acc / 8 - ref).max() / jnp.abs(ref).max())
     assert rel < 0.005, rel

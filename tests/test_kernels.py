@@ -62,7 +62,8 @@ def test_rglru(b, s, w, block):
 
 @pytest.mark.parametrize("b,s,h,p,g,n,chunk", [(2, 64, 4, 16, 2, 32, 16),
                                                (1, 48, 2, 8, 1, 16, 16),
-                                               (1, 64, 4, 16, 4, 16, 32)])
+                                               (1, 64, 4, 16, 4, 16, 32),
+                                               (1, 50, 2, 8, 1, 16, 16)])
 def test_ssd(b, s, h, p, g, n, chunk):
     from repro.kernels.ssd.ops import ssd
     from repro.kernels.ssd.ref import ssd_ref

@@ -1,5 +1,5 @@
 """TieredIO engine: async saves, crash-mid-drain safety, prefetch
-accounting, cold eviction, and the mesh version-compat helper."""
+accounting, cold eviction, and the mesh builder."""
 import time
 
 import numpy as np
@@ -273,27 +273,8 @@ def test_serve_spill_resume_via_tiered(cluster):
 
 
 # ---------------------------------------------------------------------------
-# mesh version compat (satellite regression test)
+# mesh builder
 # ---------------------------------------------------------------------------
-
-class _FakeAxisType:
-    Auto = "auto"
-
-
-class _NewSharding:
-    AxisType = _FakeAxisType
-
-
-class _OldSharding:
-    pass
-
-
-def test_mesh_axis_kwargs_both_jax_variants():
-    from repro.launch.mesh import _mesh_axis_kwargs
-    assert _mesh_axis_kwargs(2, sharding_mod=_OldSharding) == {}
-    kw = _mesh_axis_kwargs(3, sharding_mod=_NewSharding)
-    assert kw == {"axis_types": ("auto", "auto", "auto")}
-
 
 def test_make_mesh_on_installed_jax():
     from repro.launch.mesh import make_mesh
