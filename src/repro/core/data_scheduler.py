@@ -152,7 +152,7 @@ class DataScheduler:
             self._depth[nid].dec()
             sp = None
             if obs is not None and span is not None:
-                sp = obs.begin(f"sched.{label}", node=nid,
+                sp = obs.begin(f"sched.{label}", node=nid, local=True,
                                trace=span.get("trace"),
                                parent=span.get("span", 0))
             t0 = time.time()

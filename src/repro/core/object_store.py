@@ -46,6 +46,7 @@ from repro.core.pmem import PMemPool
 from repro.core.wire_codec import (codec_meta, decode_leaf,
                                    decode_leaf_tiles, encodable,
                                    encode_leaf, normalize_codec)
+from repro.obs.trace import annotate
 
 #: bounded copy granularity of the raw path — large enough to amortize
 #: call overhead, small enough that a torn source is caught within one
@@ -206,23 +207,34 @@ class PMemObjectStore:
     def put(self, name: str, tree, version: int = 0,
             meta: Optional[dict] = None) -> dict:
         leaves = _flatten(tree)
-        region_name = f"objects/{name}@v{version}.data"
         total = sum(a.nbytes for _, a in leaves)
+        # profiler spans split the write path: per-leaf copy into the
+        # region and CRC, then the flush; the rest (region create,
+        # install, manifest commit) is the span's own time
+        with annotate("store.put", node=self.pool.node_id, bytes=total):
+            return self._put(name, leaves, total, version, meta)
+
+    def _put(self, name: str, leaves, total: int, version: int,
+             meta: Optional[dict]) -> dict:
+        region_name = f"objects/{name}@v{version}.data"
         shadow = _shadow_name(region_name)
         region = self.pool.create(shadow, max(total, 1))
         manifest = {"name": name, "version": version, "ts": time.time(),
                     "meta": meta or {}, "leaves": {}, "nbytes": total}
         off = 0
         for path, arr in leaves:
-            region.write(off, arr)
+            with annotate("store.put.write"):
+                region.write(off, arr)
+            with annotate("store.put.crc"):
+                crc = zlib.crc32(np.ascontiguousarray(arr).tobytes()) \
+                    & 0xFFFFFFFF
             manifest["leaves"][path] = {
                 "shape": list(arr.shape), "dtype": str(arr.dtype),
-                "offset": off, "nbytes": arr.nbytes,
-                "crc": zlib.crc32(np.ascontiguousarray(arr).tobytes())
-                & 0xFFFFFFFF,
+                "offset": off, "nbytes": arr.nbytes, "crc": crc,
             }
             off += arr.nbytes
-        region.flush()  # CLWB+SFENCE before the commit point
+        with annotate("store.put.flush"):
+            region.flush()  # CLWB+SFENCE before the commit point
         # install the flushed shadow under the real data name (atomic;
         # a concurrent reader's old mapping stays valid on its inode)
         self.pool.rename(shadow, region_name)

@@ -288,7 +288,7 @@ from repro.core.object_store import _flatten
 from repro.core.tiering import DLMCache
 from repro.core.wire_codec import normalize_codec
 from repro.obs.metrics import Registry, StatsView
-from repro.obs.trace import ctx as _span_ctx
+from repro.obs.trace import annotate, ctx as _span_ctx
 
 
 #: acknowledged durability levels, weakest to strongest (module
@@ -750,7 +750,8 @@ class RepairChannel:
         sweep_span = None
         if obs is not None:
             # one trace per sweep: scan + every copy/re-ack hangs off it
-            sweep_span = obs.begin("repair.sweep", lost=sorted(lost))
+            sweep_span = obs.begin("repair.sweep", local=True,
+                                   lost=sorted(lost))
         sctx = _span_ctx(sweep_span)
         live = self._live(lost)
         plans: collections.deque = collections.deque()
@@ -1347,15 +1348,21 @@ class TieredIO:
                 retiring.append(self._tickets.popleft())
             self._tickets.append(ticket)
             self._g_inflight.set(len(self._tickets))
-        for old in retiring:  # wait OUTSIDE the lock: offload/prefetch
-            try:              # submissions must not stall behind a write
-                old.result()
-            except Exception as e:  # noqa: BLE001 — surfaced by
-                self.save_errors.append(e)  # raise_if_failed / quiesce
-            with self._lock:
-                self._retired.append(old)
-
         obs = self.obs
+        # wait OUTSIDE the lock: offload/prefetch submissions must not
+        # stall behind a write. The span is opened for every save, so its
+        # count is the number of saves even where no slot was taken.
+        with (obs.span("tiered.save.slot_wait", step=step)
+              if obs is not None else annotate("tiered.save.slot_wait",
+                                               step=step)):
+            for old in retiring:
+                try:
+                    old.result()
+                except Exception as e:  # noqa: BLE001 — surfaced by
+                    self.save_errors.append(e)  # raise_if_failed/quiesce
+                with self._lock:
+                    self._retired.append(old)
+
         root = None
         if obs is not None:
             # root span of the whole checkpoint trace: commit + every
@@ -1366,10 +1373,13 @@ class TieredIO:
         def _save():
             t0 = time.time()
             try:
-                man = ckpt.save(step, tree, base_step=base_step,
-                                drain=drain,
-                                post_commit=ticket.post_commit,
-                                trace=_span_ctx(root))
+                # the pmem commit on the writer thread; its store.put
+                # spans nest inside (ckpt.save_commit_s times it too)
+                with annotate("ckpt.commit", step=step):
+                    man = ckpt.save(step, tree, base_step=base_step,
+                                    drain=drain,
+                                    post_commit=ticket.post_commit,
+                                    trace=_span_ctx(root))
             except Exception:
                 if obs is not None:
                     obs.end(root, status="error")
@@ -1616,7 +1626,8 @@ class TieredIO:
         def _warm():
             obs = self.obs
             sp = obs.begin("dlm.prefetch", node=self._home_nid,
-                           n=len(names)) if obs is not None else None
+                           local=True, n=len(names)) \
+                if obs is not None else None
             hits = loads = missing = 0
             for n in names:
                 try:
@@ -1660,7 +1671,8 @@ class TieredIO:
         def _warm():
             obs = self.obs
             sp = obs.begin("exch.prefetch", node=self._home_nid,
-                           n=len(refs)) if obs is not None else None
+                           local=True, n=len(refs)) \
+                if obs is not None else None
             hits = loads = missing = 0
             from repro.core.dataset_exchange import cache_key
             for name in refs:
@@ -1708,7 +1720,7 @@ class TieredIO:
         steps 1-3). Objects already resident count as stage-in hits."""
         assert self.scheduler is not None, "no scheduler attached"
         obs = self.obs
-        sp = obs.begin("stage.stage_in", node=nid,
+        sp = obs.begin("stage.stage_in", node=nid, local=True,
                        n=len(names)) if obs is not None else None
         futs: List[Future] = []
         for name in names:

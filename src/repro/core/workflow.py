@@ -304,7 +304,7 @@ class WorkflowScheduler:
             sp = None
             if obs is not None and trace:
                 sp = obs.begin("wf.job", node=nodes[0], trace=trace,
-                               job=job.name, workflow=wf)
+                               local=True, job=job.name, workflow=wf)
             ctx = JobContext(job, nodes, self.stores, self.view,
                              workflow=wf, catalog=self.catalog,
                              external=self.external)

@@ -6,7 +6,8 @@ Three layers (ISSUE 8 / ROADMAP "Telemetry plane"):
     bounded-memory histograms; ``StatsView`` read-through aliases keep
     the legacy dict-shaped stats surfaces alive.
   * ``trace``    — correlation IDs + span trees reconstructed from
-    recorder events.
+    recorder events; ``annotate``, the profiler span every program span
+    goes through.
   * ``recorder`` — crash-persistent per-node pmem flight recorder
     (fixed-slot ring under MetaLog's committed-tail discipline).
 
@@ -17,4 +18,5 @@ from repro.obs.metrics import (Counter, Gauge, Histogram,  # noqa: F401
                                Registry, StatsView)
 from repro.obs.plane import TelemetryPlane  # noqa: F401
 from repro.obs.recorder import FlightRecorder  # noqa: F401
-from repro.obs.trace import Span, build_traces, ctx, new_id  # noqa: F401
+from repro.obs.trace import (Span, annotate, build_traces,  # noqa: F401
+                             ctx, new_id)

@@ -6,6 +6,13 @@ pool. Core modules take ``obs=None`` kwargs; a None plane (or
 in-DRAM metric update, so the library works stand-alone and the
 overhead bench can compare telemetry on/off on the same code path.
 
+Spans: ``span`` is a context manager for work that opens and closes on
+one thread and needs no ring record — a profiler span (``annotate``)
+plus the ``span.<name>.s`` histogram. ``begin``/``end`` are for
+lifecycle spans that cross threads or must survive a crash (the flight
+ring); ``begin(..., local=True)`` also opens a profiler span, for a pair
+that ``end`` closes on the thread that began it.
+
 Event routing: ``event``/``begin``/``end`` write to the named node's
 ring when it is alive, falling back to the home (first) node's ring —
 a dying node's last events land *somewhere* durable, which is the whole
@@ -16,13 +23,14 @@ after a crash the rings are the source of truth and
 """
 from __future__ import annotations
 
+import contextlib
 import time
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Iterator, Optional
 
 from repro.obs.metrics import Counter, Gauge, Histogram, Registry
 from repro.obs.recorder import EVT_BEGIN, EVT_END, EVT_POINT, \
     FlightRecorder
-from repro.obs.trace import Span, new_id
+from repro.obs.trace import Span, annotate, new_id
 
 SNAPSHOT_NAME = "obs/metrics.json"
 
@@ -87,13 +95,29 @@ class TelemetryPlane:
                     home.record(EVT_POINT, name, trace=trace, span=span,
                                 parent=parent, attrs=attrs or None)
 
+    @contextlib.contextmanager
+    def span(self, name: str, **attrs) -> Iterator[None]:
+        """A profiler span around the block that observes
+        ``span.<name>.s`` on exit, as ``end`` does; no ring record."""
+        t0 = time.time()
+        try:
+            with annotate(name, **attrs):
+                yield
+        finally:
+            self.registry.histogram(f"span.{name}.s") \
+                .observe(time.time() - t0)
+
     def begin(self, name: str, *, node: Optional[str] = None,
               trace: Optional[int] = None, parent: int = 0,
-              **attrs) -> Span:
+              local: bool = False, **attrs) -> Span:
         """Open a span (always returns a handle, even when disabled —
-        callers pass it straight back to ``end``)."""
+        callers pass it straight back to ``end``). ``local``: ``end``
+        runs on this thread, so the span is also a profiler span."""
         sp = Span(name=name, trace=trace or new_id(), span=new_id(),
                   parent=parent, node=node, t0=time.time())
+        if local:
+            sp.ann = annotate(name, **attrs)
+            sp.ann.__enter__()
         if self.enabled:
             rec = self._recorder(node)
             if rec is not None:
@@ -106,6 +130,9 @@ class TelemetryPlane:
             **attrs) -> None:
         if span is None:
             return
+        if span.ann is not None:
+            span.ann.__exit__(None, None, None)
+            span.ann = None
         t1 = time.time()
         self.registry.histogram(f"span.{span.name}.s") \
             .observe(t1 - span.t0)

@@ -10,6 +10,11 @@ thread hops and, via the flight recorder, crashes.
 
 ``build_traces`` reconstructs span trees from recorder events — shared
 by ``repro.obs.report`` and the trace-propagation tests.
+
+``annotate`` is the one door to the profiler: a span opened through it
+lands on the calling thread's line of the ``/host:CPU`` plane of a
+``jax.profiler`` trace, on the same clock as the device's ops. When no
+profiler runs it costs about a name check.
 """
 from __future__ import annotations
 
@@ -17,6 +22,8 @@ import os
 import struct
 from dataclasses import dataclass
 from typing import Dict, Iterable, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 # Flight-recorder event kinds (also the replay wire values).
 EVT_POINT = 0
@@ -32,15 +39,24 @@ def new_id() -> int:
             return v
 
 
+def annotate(name: str, **attrs) -> TraceAnnotation:
+    """A profiler span named ``name`` with ``attrs`` as its stats: a
+    context manager, entered and exited on one thread. The only place
+    the program touches ``jax.profiler.TraceAnnotation``."""
+    return TraceAnnotation(name, **attrs)
+
+
 @dataclass
 class Span:
-    """A live span handle (ended via ``TelemetryPlane.end``)."""
+    """A live span handle (ended via ``TelemetryPlane.end``). ``ann`` is
+    the open profiler span of a ``local`` begin."""
     name: str
     trace: int
     span: int
     parent: int = 0
     node: Optional[str] = None
     t0: float = 0.0
+    ann: Optional[TraceAnnotation] = None
 
 
 def ctx(span: Optional[Span]) -> Optional[dict]:

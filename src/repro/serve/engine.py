@@ -32,6 +32,7 @@ from repro.configs.base import ModelConfig
 from repro.core.object_store import PMemObjectStore
 from repro.core.tiered_io import TieredIO
 from repro.models import transformer as tfm
+from repro.obs.trace import annotate
 
 
 class SpillTicket:
@@ -108,14 +109,29 @@ class ServeEngine:
         return np.asarray(jnp.argmax(logits, axis=-1), np.int32)
 
     def decode(self, first_tokens: np.ndarray, steps: int) -> np.ndarray:
-        toks = jnp.asarray(first_tokens)
-        out = [np.asarray(toks)]
-        for i in range(steps):
-            logits, self.cache = self._decode(
-                self.params, self.cache, toks, jnp.int32(self.pos))
-            toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        """``steps`` greedy tokens after ``first_tokens``. Each token is
+        three profiler spans on the caller's thread: ``engine.decode.step``
+        (argument prep and the jitted step's dispatch), ``.sample``
+        (argmax dispatch) and ``.sync`` (the wait for the token on the
+        host); the call adds its tokens and syncs to the counters
+        ``serve.decode.tokens`` and ``serve.decode.host_syncs``."""
+        out = [np.asarray(first_tokens, np.int32)]
+        toks = jnp.asarray(out[0])
+        syncs = 0
+        for _ in range(steps):
+            with annotate("engine.decode.step"):
+                logits, self.cache = self._decode(
+                    self.params, self.cache, toks, jnp.int32(self.pos))
+            with annotate("engine.decode.sample"):
+                toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             self.pos += 1
-            out.append(np.asarray(toks))
+            with annotate("engine.decode.sync"):
+                out.append(np.asarray(toks))
+            syncs += 1
+        obs = self._obs()
+        if obs is not None:
+            obs.counter("serve.decode.tokens").inc(steps)
+            obs.counter("serve.decode.host_syncs").inc(syncs)
         return np.stack(out, axis=1)
 
     # ---- session-state handoff (the SessionManager's interface) ----
@@ -178,7 +194,7 @@ class ServeEngine:
 
     def resume(self, name: str) -> None:
         obs = self._obs()
-        sp = obs.begin("serve.resume", session=name) \
+        sp = obs.begin("serve.resume", local=True, session=name) \
             if obs is not None else None
         if self.tiered is not None:
             obj = self.tiered.fetch(f"serve/{name}")

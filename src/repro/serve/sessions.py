@@ -38,8 +38,9 @@ Dataset* in the existing exchange catalog:
     are ``@metadata_only``: they answer from catalog records and the
     in-DRAM session table — zero object-store probes, lint-enforced;
   * every lifecycle edge is instrumented through the TelemetryPlane:
-    ``serve.sessions_active`` gauge, ``serve.resume_ms`` /
-    ``serve.spill_to_ack_s`` histograms, and ONE trace-span tree per
+    ``serve.sessions_active`` gauge, the ``serve.spill_to_ack_s``
+    histogram, the ``serve.resume`` / ``serve.spill`` spans (their
+    ``span.<name>.s`` histograms), and ONE trace-span tree per
     session lifetime (the root span's trace id is persisted in the
     record's annotations, so the tree reconnects across processes).
 
@@ -107,7 +108,6 @@ class SessionManager:
         self.obs = obs
         reg = obs.registry if obs is not None else Registry()
         self._g_active = reg.gauge("serve.sessions_active")
-        self._h_resume_ms = reg.histogram("serve.resume_ms")
         self._h_spill_to_ack = reg.histogram("serve.spill_to_ack_s")
         self._c_spills = reg.counter("serve.spills")
         self._c_resumes = reg.counter("serve.resumes")
@@ -296,8 +296,11 @@ class SessionManager:
             with self._lock:
                 sess.engine = None
             self._g_active.dec()
+        # a waited spill ends on this thread; an async one on the I/O
+        # thread, so only the waited one is a profiler span
         sp = self._begin("serve.spill", sess, session=name,
-                         release=release)
+                         release=release,
+                         local=wait or self.tiered is None)
         t0 = time.time()
         if wait or self.tiered is None:
             try:
@@ -334,9 +337,8 @@ class SessionManager:
         session this process has never seen is adopted from its catalog
         record — including the persisted trace id, so the lifetime span
         tree continues across processes."""
-        t0 = time.perf_counter()
         sess = self._adopt(name)
-        sp = self._begin("serve.resume", sess, session=name)
+        sp = self._begin("serve.resume", sess, session=name, local=True)
         with self._lock:
             if sess.engine is not None:
                 raise RuntimeError(f"session {name!r} already bound")
@@ -357,7 +359,6 @@ class SessionManager:
             sess.last_used = time.time()
         self._g_active.inc()
         self._c_resumes.inc()
-        self._h_resume_ms.observe((time.perf_counter() - t0) * 1e3)
         self._end(sp, parked=parked is not None)
 
     def _adopt(self, name: str) -> _Session:

@@ -1,8 +1,9 @@
 """Training loop: steps + the paper's systemware hooks.
 
 Per step: train_step (jit) -> heartbeat -> straggler stats. Every
-``ckpt_every`` steps the loop hands the (host-fetched) state to the
-TieredIO engine via ``save_async`` — even the node-local pmem write now
+``ckpt_every`` steps the loop copies the state to the host (span
+``train.ckpt.d2h``) and hands it to the TieredIO engine via
+``save_async`` (span ``train.ckpt.submit``) — even the node-local pmem write now
 overlaps the next step's compute; the loop only ever blocks on slot
 backpressure (a write two checkpoints old still in flight). In-flight
 futures are joined exactly twice: at clean shutdown, and (via
@@ -91,12 +92,15 @@ def run(train_step_fn: Callable, params, opt_state,
                 # surface now, not after hours of unprotected training
                 cluster.tiered.raise_if_failed()
                 t0 = time.time()
-                host_state = {"params": jax.tree.map(np.asarray, params),
-                              "opt": jax.tree.map(np.asarray, opt_state)}
+                with cluster.obs.span("train.ckpt.d2h", step=step + 1):
+                    host_state = {
+                        "params": jax.tree.map(np.asarray, params),
+                        "opt": jax.tree.map(np.asarray, opt_state)}
                 base = last_full if loop_cfg.delta_ckpt else None
-                last_ticket = cluster.tiered.save_async(
-                    step + 1, host_state, base_step=base,
-                    drain=bool(loop_cfg.drain_every))
+                with cluster.obs.span("train.ckpt.submit", step=step + 1):
+                    last_ticket = cluster.tiered.save_async(
+                        step + 1, host_state, base_step=base,
+                        drain=bool(loop_cfg.drain_every))
                 if not loop_cfg.delta_ckpt or last_full is None:
                     last_full = step + 1
                 # what the step pays: the submit (+ slot backpressure)

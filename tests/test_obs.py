@@ -3,6 +3,7 @@ and end-to-end trace propagation (PR 8)."""
 import tempfile
 from pathlib import Path
 
+import numpy as np
 import pytest
 
 from repro.core.pmem import PMemPool
@@ -255,11 +256,20 @@ def test_workflow_jobs_share_one_trace(cluster):
 
 def test_telemetry_off_records_nothing(tmp_path):
     from repro.core.cluster import SimCluster
+    from test_obs_profile import _engine, _train
     c = SimCluster(tmp_path, n_nodes=2, telemetry=False)
     c.tiered.save_async(0, {"w": b"\x07" * 128}).result()
     c.tiered.quiesce()
     c.checkpointer.wait_async()
     assert c.tiered.stats["saves"] == 1  # DRAM metrics still work
+    # the profiler spans of the checkpoint path and the decode loop
+    # (train.ckpt.*, tiered.save.slot_wait, ckpt.commit, store.put.*,
+    # engine.decode.*) write no ring event either
+    _train(c, 2)
+    _engine(c).decode(np.zeros(1, np.int32), 3)
+    assert c.tiered.stats["saves"] == 3
+    hist = c.obs.snapshot()["histograms"]
+    assert hist["span.train.ckpt.d2h.s"]["count"] == 2
     for pool in c.pools.values():
         assert FlightRecorder.replay(pool) == []
     c.shutdown()

@@ -364,7 +364,7 @@ def test_session_telemetry_surfaces(cluster):
     snap = cluster.obs.snapshot()
     assert snap["counters"]["serve.spills"] >= 1
     assert snap["counters"]["serve.resumes"] >= 1
-    assert snap["histograms"]["serve.resume_ms"]["count"] >= 1
+    assert snap["histograms"]["span.serve.resume.s"]["count"] >= 1
     # spill-to-ack probe fires once the buddy ack lands
     cluster.tiered.quiesce()
     deadline = time.time() + 10
