@@ -48,9 +48,9 @@ from repro.core.wire_codec import (codec_meta, decode_leaf,
                                    encode_leaf, normalize_codec)
 from repro.obs.trace import annotate
 
-#: bounded copy granularity of the raw path — large enough to amortize
-#: call overhead, small enough that a torn source is caught within one
-#: chunk and peak extra memory stays bounded
+#: bounded copy granularity of the raw path and of ``put`` — large
+#: enough to amortize call overhead, small enough that a torn source is
+#: caught within one chunk and that a chunk is still in cache for its CRC
 DEFAULT_CHUNK_BYTES = 8 << 20
 
 
@@ -208,9 +208,9 @@ class PMemObjectStore:
             meta: Optional[dict] = None) -> dict:
         leaves = _flatten(tree)
         total = sum(a.nbytes for _, a in leaves)
-        # profiler spans split the write path: per-leaf copy into the
-        # region and CRC, then the flush; the rest (region create,
-        # install, manifest commit) is the span's own time
+        # profiler spans split the write path: each chunk's copy into
+        # the region and its CRC, then the flush; the rest (region
+        # create, install, manifest commit) is the span's own time
         with annotate("store.put", node=self.pool.node_id, bytes=total):
             return self._put(name, leaves, total, version, meta)
 
@@ -223,14 +223,20 @@ class PMemObjectStore:
                     "meta": meta or {}, "leaves": {}, "nbytes": total}
         off = 0
         for path, arr in leaves:
-            with annotate("store.put.write"):
-                region.write(off, arr)
-            with annotate("store.put.crc"):
-                crc = zlib.crc32(np.ascontiguousarray(arr).tobytes()) \
-                    & 0xFFFFFFFF
+            # one pass per byte: each chunk is copied into the region,
+            # then folded into the leaf's CRC while it is still in cache
+            buf = np.ascontiguousarray(arr).view(np.uint8).reshape(-1)
+            crc = 0
+            for lo in range(0, buf.nbytes, DEFAULT_CHUNK_BYTES):
+                chunk = buf[lo:lo + DEFAULT_CHUNK_BYTES]
+                with annotate("store.put.write"):
+                    region.write(off + lo, chunk)
+                with annotate("store.put.crc"):
+                    crc = zlib.crc32(chunk, crc)
             manifest["leaves"][path] = {
                 "shape": list(arr.shape), "dtype": str(arr.dtype),
-                "offset": off, "nbytes": arr.nbytes, "crc": crc,
+                "offset": off, "nbytes": arr.nbytes,
+                "crc": crc & 0xFFFFFFFF,
             }
             off += arr.nbytes
         with annotate("store.put.flush"):
