@@ -78,8 +78,7 @@ def main(argv=None) -> None:
         finally:
             if trace_dir:
                 shutil.rmtree(trace_dir, ignore_errors=True)
-        rec.update(model=cell.config["model"], traffic=cell.traffic,
-                   peak=None, trace=None)
+        bench.annotate(rec, cell, None, None)
         line = {"workload": cell.name, "seed": seed, "rate": args.rate,
                 "compared": {c.name: c.value for c in rec["compared"]},
                 "controls": rec["controls"],

@@ -96,6 +96,11 @@ class Cell:
         """The plain reference module beside the configuration."""
         return load_module(BENCH / "configs" / self.config["reference"])
 
+    def weight_rules(self) -> Dict[str, Any]:
+        """The reference's ``WEIGHT_RULES`` for leaves the harness's table
+        lacks (``harness/weights.py``); none by default."""
+        return getattr(self.reference(), "WEIGHT_RULES", {})
+
 
 def _applies(metric: dict, cell: str) -> bool:
     return cell in metric.get("workloads", [cell])

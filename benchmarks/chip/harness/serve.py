@@ -192,7 +192,7 @@ def check(cell, seed: int, sessions: List[Session], shapes,
             break
         sample.append(s)
         n += len(s.served)
-    w = weights.make(seed, shapes)
+    w = weights.make(seed, shapes, rules=cell.weight_rules())
     out = {q: 0.0 for q in quants}
     close = distinct = 0
     for s in sample:
@@ -249,7 +249,8 @@ def run(cell, seed: int, seconds: float, trace_dir: Optional[str],
     cluster = SimCluster(root, n_nodes=int(tr["nodes"]),
                          pmem_capacity=int(tr["pmem_bytes_per_node"]))
     try:
-        eng = ServeEngine(cfg, rt, weights.make(seed, shapes),
+        eng = ServeEngine(cfg, rt, weights.make(seed, shapes,
+                                                rules=cell.weight_rules()),
                           tiered=cluster.tiered)
         sm = cluster.sessions
         warm(eng, sm, sched.pool_prompts + [t.prompt_len for t in

@@ -137,7 +137,8 @@ def setup(cell, seed: int, root):
     shapes = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
                           trainer.params)
     trainer.params = None
-    trainer.params = weights.make(seed, shapes, trainer.shardings[0])
+    trainer.params = weights.make(seed, shapes, trainer.shardings[0],
+                                  cell.weight_rules())
     nbytes = trainer.state_bytes()
     cluster = SimCluster(root, n_nodes=int(tr["nodes"]),
                          pmem_capacity=max(1 << 32, 4 * nbytes))
@@ -272,7 +273,8 @@ def run(cell, seed: int, seconds: float, trace_dir: Optional[str],
                     lambda mo: mo["m"], opt_state["moments"],
                     is_leaf=lambda x: isinstance(x, dict) and "m" in x))
             if n == k:
-                p0 = weights.make(seed, shapes, trainer.shardings[0])
+                p0 = weights.make(seed, shapes, trainer.shardings[0],
+                                  cell.weight_rules())
                 hooks["change"] = change_norms(params, p0)
                 del p0
 
@@ -350,7 +352,7 @@ def run(cell, seed: int, seconds: float, trace_dir: Optional[str],
         f"wrong")
     t = time.perf_counter()
     ref_mod = cell.reference()
-    w = weights.make(seed, shapes)
+    w = weights.make(seed, shapes, rules=cell.weight_rules())
     m = cell.config["model"]
     ref = ref_mod.train(w, m, opt, first)
     log(f"reference: {time.perf_counter() - t:.3f} s")

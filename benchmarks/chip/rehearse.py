@@ -3,16 +3,22 @@
 with no chip attached, and print each one's ``memory_analysis()``.
 
     JAX_PLATFORMS=cpu python3 benchmarks/chip/rehearse.py
+    JAX_PLATFORMS=cpu python3 benchmarks/chip/rehearse.py \
+        --config <file.json> [--prompt N] [--max-seq N]
 
 Programs: the starcoder2-15b-10l prefill of the longest prompt (3584
 tokens) and its decode step over a 4096-token cache; the mamba2-1.3b
 prefill of 4096 tokens with the SSD kernel and its decode step; the
 mamba2-1.3b-4l train step at the train cell's batch. Each is lowered on
 shapes alone, as the benchmark calls it, on device 0 of a described
-``v5e:2x2`` topology.
+``v5e:2x2`` topology. With ``--config`` it compiles that configuration
+file's prefill of ``--prompt`` tokens and its decode step over a
+``--max-seq`` cache instead (SSD layers through the kernel), so that a
+new configuration's cut can be sized before any chip run.
 """
 from __future__ import annotations
 
+import argparse
 import functools
 import json
 import os
@@ -39,20 +45,17 @@ def _report(name: str, compiled) -> dict:
     return out
 
 
-def serve_programs(cfg_file: str, prompt: int, dev) -> None:
+def serve_programs(cj: dict, prompt: int, max_seq: int, ssd_impl: str,
+                   dev) -> None:
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
     from harness import model
     from repro.models import transformer as tfm
-    cj = json.loads((HERE / "configs" / cfg_file).read_text())
-    tr = json.loads((HERE / "traffic" / (
-        "serve-code.json" if "starcoder" in cfg_file else
-        "serve-chat.json")).read_text())
     cfg = model.program_config(cj)
-    rt = tfm.ModelRuntime(tp=1, ssd_impl=tr["ssd_impl"],
-                          max_seq=int(tr["max_seq"]), remat=False)
+    rt = tfm.ModelRuntime(tp=1, ssd_impl=ssd_impl, max_seq=max_seq,
+                          remat=False)
     one = SingleDeviceSharding(dev)
 
     def on_dev(tree):
@@ -114,15 +117,31 @@ def train_program(dev) -> None:
             step.lower(p_shapes, o_shapes, batch).compile())
 
 
-def main() -> None:
+def _cell_serve(cfg_file: str, traffic: str, prompt: int, dev) -> None:
+    cj = json.loads((HERE / "configs" / cfg_file).read_text())
+    tr = json.loads((HERE / "traffic" / traffic).read_text())
+    serve_programs(cj, prompt, int(tr["max_seq"]), tr["ssd_impl"], dev)
+
+
+def main(argv=None) -> None:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument("--config", help="a configuration file to compile")
+    ap.add_argument("--prompt", type=int, default=4096)
+    ap.add_argument("--max-seq", type=int, default=4096)
+    args = ap.parse_args(argv)
+
     import jax
     from jax.experimental import topologies
     jax.config.update("jax_enable_compilation_cache", False)
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     dev = topo.devices[0]
-    serve_programs("starcoder2-15b-10l.json", 3584, dev)
-    serve_programs("mamba2-1.3b.json", 4096, dev)
+    if args.config:
+        cj = json.loads(Path(args.config).read_text())
+        serve_programs(cj, args.prompt, args.max_seq, "pallas", dev)
+        return
+    _cell_serve("starcoder2-15b-10l.json", "serve-code.json", 3584, dev)
+    _cell_serve("mamba2-1.3b.json", "serve-chat.json", 4096, dev)
     train_program(dev)
 
 

@@ -66,6 +66,13 @@ def read_metrics(cell, record: dict) -> dict:
     return out
 
 
+def annotate(record: dict, cell, peak, summary) -> None:
+    """Add what the metric readers read beside a driver's record: the
+    configuration's sizes, the traffic, the chip's peaks, the trace."""
+    record.update(model=cell.config["model"], traffic=cell.traffic,
+                  peak=peak, trace=summary)
+
+
 def execute(cell, seed: int, seconds: float, trace: bool, devices,
             clock) -> dict:
     """Run the cell on ``devices`` and return the result line's fields."""
@@ -84,8 +91,7 @@ def execute(cell, seed: int, seconds: float, trace: bool, devices,
     finally:
         if trace_dir:
             shutil.rmtree(trace_dir, ignore_errors=True)
-    record.update(model=cell.config["model"], traffic=cell.traffic,
-                  peak=peak, trace=summary)
+    annotate(record, cell, peak, summary)
     device = common.device_info(devices)
     device["memory_peak_bytes"] = record["memory_peak_bytes"]
     breakdown = None

@@ -70,9 +70,8 @@ def measure(cell, e2e_cell, seed: int, seconds: float, clock,
     if trace_out:
         Path(trace_out).write_text(json.dumps(
             stretch(ptr, trace_ms).to_json()))
-    record.update(model=cell.config["model"], traffic=cell.traffic,
-                  peak=peaks.peak(clock.devices[0].device_kind),
-                  trace=summary)
+    bench.annotate(record, cell, peaks.peak(clock.devices[0].device_kind),
+                   summary)
     sp = ps.split(ptr)
     common.log(ps.log_line(sp))
     ended = {n: len(v) for n, v in sorted(sp.ended.items())

@@ -4,6 +4,8 @@ the program's place, fail it. Tiny sizes on the CPU; the limits are the
 tiny benchmark's (conftest.LIMITS)."""
 from __future__ import annotations
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,7 +14,8 @@ import pytest
 from conftest import LIMITS
 
 
-@pytest.mark.parametrize("workload", ["t-train", "t-code", "t-chat"])
+@pytest.mark.parametrize("workload", ["t-train", "t-code", "t-chat",
+                                      "t-hybrid"])
 def test_sound_run_is_correct(drive, workload):
     out = drive(workload)
     assert out["correct"], [(c.name, c.value, c.limit)
@@ -113,11 +116,54 @@ def _state_lost(monkeypatch):
 
 
 @pytest.mark.parametrize("fault", [_token_altered, _state_lost])
-@pytest.mark.parametrize("workload", ["t-code", "t-chat"])
+@pytest.mark.parametrize("workload", ["t-code", "t-chat", "t-hybrid"])
 def test_serve_fault_is_caught(drive, monkeypatch, workload, fault):
     fault(monkeypatch)
     out = drive(workload)
     assert not out["correct"]
+
+
+def _wrap_moe(monkeypatch, wrap):
+    from repro.models import moe
+    monkeypatch.setattr(moe, "apply_moe_gshard", wrap(moe.apply_moe_gshard))
+
+
+def _expert_dropped(monkeypatch):
+    def wrap(moe_fn):
+        def dropped(p, x, cfg):
+            return moe_fn(dict(p, wo=p["wo"].at[:, 0].set(0)), x, cfg)
+        return dropped
+    _wrap_moe(monkeypatch, wrap)
+
+
+def _top_k_less(monkeypatch):
+    def wrap(moe_fn):
+        def fewer(p, x, cfg):
+            moe = dataclasses.replace(cfg.moe, top_k=cfg.moe.top_k - 1)
+            return moe_fn(p, x, dataclasses.replace(cfg, moe=moe))
+        return fewer
+    _wrap_moe(monkeypatch, wrap)
+
+
+def _attention_cache_lost(monkeypatch):
+    from repro.serve.sessions import SessionManager
+    orig = SessionManager.resume
+
+    def resume(self, name, engine):
+        orig(self, name, engine)
+        engine.cache = jax.tree_util.tree_map_with_path(
+            lambda path, a: jnp.zeros_like(a)
+            if path[-1].key in ("k", "v") else a, engine.cache)
+    monkeypatch.setattr(SessionManager, "resume", resume)
+
+
+@pytest.mark.parametrize("fault", [_expert_dropped, _top_k_less,
+                                   _attention_cache_lost])
+def test_hybrid_expert_fault_is_caught(drive, monkeypatch, fault):
+    fault(monkeypatch)
+    out = drive("t-hybrid")
+    assert not out["correct"], [(c.name, c.value, c.limit)
+                                for c in out["compared"]]
 
 
 def _cell(tiny, name):
@@ -135,7 +181,7 @@ def test_train_controls_fail(tiny, drive):
         assert any(v > LIMITS[k] for k, v in read.items()), (name, read)
 
 
-@pytest.mark.parametrize("workload", ["t-code", "t-chat"])
+@pytest.mark.parametrize("workload", ["t-code", "t-chat", "t-hybrid"])
 def test_serve_control_fails(tiny, drive, workload):
     import run as bench
     from harness import serve
