@@ -1,4 +1,5 @@
-"""Architecture registry: --arch <id> resolution and the 40-cell matrix."""
+"""Architecture registry: --arch <id> resolution and the (arch x shape)
+cell matrix."""
 from __future__ import annotations
 
 import importlib
@@ -19,7 +20,11 @@ _ARCH_MODULES = {
     "arctic-480b": "arctic_480b",
     "mamba2-1.3b": "mamba2_1p3b",
     "internvl2-26b": "internvl2_26b",
+    "nemotron3-nano-30b-a3b": "nemotron3_nano",
+    "nemotron3-nano-30b-a3b-ep16": "nemotron3_nano",
 }
+# arch id -> the module's config function, where it is not ``config``
+_ARCH_FUNCS = {"nemotron3-nano-30b-a3b-ep16": "ep16_config"}
 
 ARCH_IDS = tuple(_ARCH_MODULES)
 
@@ -31,7 +36,7 @@ def _mod(arch: str):
 
 
 def get_config(arch: str) -> ModelConfig:
-    return _mod(arch).config()
+    return getattr(_mod(arch), _ARCH_FUNCS.get(arch, "config"))()
 
 
 def get_smoke_config(arch: str) -> ModelConfig:
@@ -59,7 +64,7 @@ def cell_skip_reason(cfg: ModelConfig, shape: ShapeConfig) -> Optional[str]:
 
 def cells(arch: Optional[str] = None,
           shape: Optional[str] = None) -> Iterator[Cell]:
-    """All (arch x shape) cells, skip-annotated. 10 archs x 4 shapes = 40."""
+    """All (arch x shape) cells, skip-annotated: archs x 4 shapes."""
     archs = [arch] if arch else list(ARCH_IDS)
     shapes = [SHAPES[shape]] if shape else list(SHAPES.values())
     for a in archs:

@@ -159,7 +159,7 @@ class Plan:
     def runtime(self):
         from repro.distributed import decode_attn as da
         from repro.distributed import moe_parallel as mp
-        from repro.models.moe import can_use_2d
+        from repro.models.moe import can_use_2d, etp_supported
         from repro.models.transformer import ModelRuntime
         tp = self.tp
         decode_fn = None
@@ -169,13 +169,14 @@ class Plan:
         dp = 1
         for a in baxes:
             dp *= self.mesh.shape[a]
+        etp = self.cfg.moe is not None and etp_supported(self.cfg)
         if tp > 1 or baxes:
             if self.shape.kind == DECODE:
                 decode_fn = da.make_flash_decode(self.mesh)
-                if self.cfg.moe:
+                if etp:
                     moe_fn = mp.make_moe_replicated(self.mesh,
                                                     expert_2d=True)
-            elif self.cfg.moe:
+            elif etp:
                 last = self.mesh.shape[baxes[-1]] if baxes else 0
                 if can_use_2d(self.cfg, tp, dp, last):
                     moe_fn = mp.make_moe_etp2d(self.mesh)

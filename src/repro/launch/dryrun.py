@@ -202,7 +202,9 @@ def build_and_compile(arch: str, shape_name: str, multi_pod: bool,
             (shape.global_batch, cfg.padded_vocab), mesh))
 
         def serve_step(params, cache, tokens, pos):
-            return tfm.decode_step(params, cfg, rt, cache, tokens, pos)
+            logits, cache, _ = tfm.decode_step(params, cfg, rt, cache,
+                                               tokens, pos)
+            return logits, cache
 
         jitted = jax.jit(
             serve_step,

@@ -118,11 +118,11 @@ def init_norm(pb: ParamBuilder, name: str, d: int, kind: str,
         c.param("b", (d,), (None,), init="zeros")
 
 
-def apply_norm(p: Params, x: jax.Array, kind: str,
-               gemma_scale: bool) -> jax.Array:
+def apply_norm(p: Params, x: jax.Array, kind: str, gemma_scale: bool,
+               rms_eps: float = 1e-6) -> jax.Array:
     if kind == "layernorm":
         return layer_norm(x, p["w"], p["b"])
-    return rms_norm(x, p["w"], gemma_scale=gemma_scale)
+    return rms_norm(x, p["w"], eps=rms_eps, gemma_scale=gemma_scale)
 
 
 # ---------------------------------------------------------------------------
@@ -176,6 +176,8 @@ def apply_mlp(p: Params, x: jax.Array, kind: str) -> jax.Array:
         h = jax.nn.gelu(h) * jnp.einsum("...d,df->...f", x, p["w3"])
     elif kind == "gelu":
         h = jax.nn.gelu(h)
+    elif kind == "relu2":
+        h = jnp.square(jax.nn.relu(h))
     else:
         raise ValueError(kind)
     y = jnp.einsum("...f,fd->...d", h, p["w2"])

@@ -1,9 +1,12 @@
 """Mamba2 SSD (state-space duality) blocks.
 
-Block: in_proj -> (z, x, B, C, dt); causal conv over (x,B,C); SSD scan;
-gated RMSNorm; out_proj. The SSD scan is the chunked algorithm from
-arXiv:2405.21060 (intra-chunk quadratic term + inter-chunk state
-recurrence); kernels/ssd provides the Pallas fast path.
+Block: in_proj -> (z, x, B, C, dt); causal conv over (x,B,C) (with a bias
+where ``SSMConfig.conv_bias``); SSD scan; gated RMSNorm, taken per group of
+d_inner / n_groups channels; out_proj. The inner width is ``n_heads *
+head_dim`` where the config sets a head count, else ``expand * d_model``.
+The SSD scan is the chunked algorithm from arXiv:2405.21060 (intra-chunk
+quadratic term + inter-chunk state recurrence); kernels/ssd provides the
+Pallas fast path.
 
 Shapes: x [B,S,H,P], dt [B,S,H], A [H] (negative), B/C [B,S,G,N] (G groups
 broadcast over heads).
@@ -23,7 +26,7 @@ Params = Dict[str, Any]
 
 def ssm_dims(cfg: ModelConfig) -> Tuple[int, int, int, int, int]:
     sc = cfg.ssm
-    d_inner = sc.expand * cfg.d_model
+    d_inner = sc.d_inner(cfg.d_model)
     n_heads = d_inner // sc.head_dim
     return d_inner, n_heads, sc.head_dim, sc.n_groups, sc.d_state
 
@@ -38,6 +41,9 @@ def init_ssd(pb: ParamBuilder, cfg: ModelConfig) -> None:
     pb.param("wdt", (d, h), (None, "ssm_heads"), init="fan_in")
     pb.param("conv_x", (d_inner, cw), ("ssm_flat", None), init="fan_in")
     pb.param("conv_bc", (2 * g * n, cw), (None, None), init="fan_in")
+    if cfg.ssm.conv_bias:
+        pb.param("conv_x_b", (d_inner,), ("ssm_flat",), init="zeros")
+        pb.param("conv_bc_b", (2 * g * n,), (None,), init="zeros")
     pb.param("a_log", (h,), ("ssm_heads",), init="ssm_a")
     pb.param("d_skip", (h,), ("ssm_heads",), init="ones")
     pb.param("dt_bias", (h,), ("ssm_heads",), init="ssm_dt")
@@ -170,7 +176,11 @@ def apply_ssd(p: Params, xin: jax.Array, cfg: ModelConfig,
             jnp.zeros((B_, cw - 1, conv_in.shape[-1]), conv_in.dtype)
         new_conv = jnp.concatenate([prev.astype(conv_in.dtype), conv_in],
                                    axis=1)[:, -(cw - 1):]
-    conv_out = jax.nn.silu(conv1d_channels(conv_in, conv_w, carry))
+    conv_out = conv1d_channels(conv_in, conv_w, carry)
+    if "conv_x_b" in p:
+        conv_out = conv_out + jnp.concatenate(
+            [p["conv_x_b"], p["conv_bc_b"]]).astype(conv_out.dtype)
+    conv_out = jax.nn.silu(conv_out)
     x = conv_out[..., :d_inner].reshape(B_, S, H, P)
     b = conv_out[..., d_inner:d_inner + G * N].reshape(B_, S, G, N)
     c = conv_out[..., d_inner + G * N:].reshape(B_, S, G, N)
@@ -191,8 +201,13 @@ def apply_ssd(p: Params, xin: jax.Array, cfg: ModelConfig,
         new_state = {"h": h_new, "conv": new_conv}
 
     y = y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype)
-    y = rms_norm(y.reshape(B_, -1, H * P),
-                 p["norm_w"].reshape(-1)).reshape(y.shape)
+    if G == 1:
+        y = rms_norm(y.reshape(B_, -1, H * P), p["norm_w"].reshape(-1),
+                     eps=cfg.rms_eps).reshape(y.shape)
+    else:  # per group of the heads that share B and C
+        y = rms_norm(y.reshape(B_, -1, G, H * P // G),
+                     p["norm_w"].reshape(G, -1),
+                     eps=cfg.rms_eps).reshape(y.shape)
     out = jnp.einsum("bshp,hpd->bsd", y, p["w_out"])
     return out, new_state
 

@@ -1,10 +1,12 @@
-"""Block-pattern transformer LM: one implementation, ten architectures.
+"""Block-pattern transformer LM: one implementation, every architecture.
 
 A model is a repeating pattern of (mixer, mlp) layer specs (configs/base.py).
 Layers are *stacked by period position* and executed with ``lax.scan`` over
 period repeats (+ remat on the body), so HLO size and compile time are
-independent of depth. Covers dense/GQA, MoE, SSM (Mamba2), hybrid
-(RecurrentGemma), encoder-decoder (Whisper) and VLM-prefix (InternVL) forms.
+independent of depth; a non-periodic sequence is cut into repeated runs
+(``ModelConfig.groups``). Covers dense/GQA, MoE, SSM (Mamba2), hybrid
+(RecurrentGemma; Nemotron-H, whose blocks hold a mixer or an MLP),
+encoder-decoder (Whisper) and VLM-prefix (InternVL) forms.
 
 Distribution is injected through ``ModelRuntime``: sharding-constraint hook,
 TP degree (for head/vocab padding layouts), and optional shard_map
@@ -19,8 +21,8 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import (ATTN_GLOBAL, ATTN_LOCAL, MLP_MOE, MLP_NONE,
-                                RGLRU, SSD, LayerSpec, ModelConfig)
+from repro.configs.base import (ATTN_GLOBAL, ATTN_LOCAL, MIXER_NONE, MLP_MOE,
+                                MLP_NONE, RGLRU, SSD, LayerSpec, ModelConfig)
 from repro.models import attention as attn_mod
 from repro.models import moe as moe_mod
 from repro.models import rglru as rglru_mod
@@ -62,7 +64,8 @@ def _init_layer(key: jax.Array, cfg: ModelConfig, spec: LayerSpec,
                 causal: bool = True) -> Tuple[Params, Params]:
     pb = ParamBuilder(key, dtype=jnp.bfloat16)
     gemma = cfg.norm == "rmsnorm" and cfg.post_norms
-    init_norm(pb, "norm1", cfg.d_model, cfg.norm, gemma)
+    if spec.mixer != MIXER_NONE:
+        init_norm(pb, "norm1", cfg.d_model, cfg.norm, gemma)
     layout = rt.head_layout(cfg)
     dh = cfg.resolved_head_dim
     if spec.mixer in (ATTN_GLOBAL, ATTN_LOCAL):
@@ -74,9 +77,9 @@ def _init_layer(key: jax.Array, cfg: ModelConfig, spec: LayerSpec,
         rglru_mod.init_rglru(pb.child("mixer"), cfg)
     elif spec.mixer == SSD:
         ssm_mod.init_ssd(pb.child("mixer"), cfg)
-    else:
+    elif spec.mixer != MIXER_NONE:
         raise ValueError(spec.mixer)
-    if cfg.post_norms:
+    if cfg.post_norms and spec.mixer != MIXER_NONE:
         init_norm(pb, "post_norm1", cfg.d_model, cfg.norm, gemma)
     if cross:
         init_norm(pb, "norm_cross", cfg.d_model, cfg.norm, gemma)
@@ -165,8 +168,8 @@ def _apply_attn_full(lp: Params, x: jax.Array, spec: LayerSpec,
     q, k, v = attn_mod.qkv_project(lp, x, kv_x)
     kv_pos = positions if kv_x is None else \
         jnp.arange(kv_x.shape[1], dtype=positions.dtype)
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, kv_pos, cfg.rope_theta)
+    q = rope(q, positions, cfg.rotary_theta)
+    k = rope(k, kv_pos, cfg.rotary_theta)
     window = cfg.window if spec.mixer == ATTN_LOCAL else 0
     o = attn_mod.attend(q, k, v, causal=causal, window=window,
                         cap=cfg.attn_softcap, impl=rt.attn_impl)
@@ -228,8 +231,8 @@ def _apply_attn_decode(lp: Params, x: jax.Array, spec: LayerSpec,
     if "bq" in lp:
         q = q + lp["bq"]
     positions = pos[None]  # [S=1]
-    if cfg.rope_theta > 0:
-        q = rope(q[:, None], positions, cfg.rope_theta)[:, 0]
+    if cfg.rotary_theta > 0:
+        q = rope(q[:, None], positions, cfg.rotary_theta)[:, 0]
     if cross:
         k_new = v_new = None
     else:
@@ -237,7 +240,7 @@ def _apply_attn_decode(lp: Params, x: jax.Array, spec: LayerSpec,
         v_new = jnp.einsum("bd,dhk->bhk", x[:, 0], lp["wv"])
         if "bk" in lp:
             k_new, v_new = k_new + lp["bk"], v_new + lp["bv"]
-        k_new = rope(k_new[:, None], positions, cfg.rope_theta)[:, 0]
+        k_new = rope(k_new[:, None], positions, cfg.rotary_theta)[:, 0]
     window = cfg.window if spec.mixer == ATTN_LOCAL else 0
     o, new_cache = _decode_attn(rt, cache, k_new, v_new, q, pos,
                                 window=window, cap=cfg.attn_softcap)
@@ -248,11 +251,58 @@ def _apply_attn_decode(lp: Params, x: jax.Array, spec: LayerSpec,
 def apply_layer(lp: Params, x: jax.Array, spec: LayerSpec, cfg: ModelConfig,
                 rt: ModelRuntime, *, mode: str, positions=None, cache=None,
                 enc_out=None, pos=None, causal: bool = True):
-    """mode: full | prefill | decode. Returns (x, cache_out, aux)."""
+    """mode: full | prefill | decode. Returns (x, cache_out, aux, held):
+    ``held`` counts the router's picks that land on the experts held here
+    (0 for a layer with no experts or with ``rt.moe_fn``)."""
     gemma = cfg.norm == "rmsnorm" and cfg.post_norms
     aux = jnp.zeros((), jnp.float32)
+    held = jnp.zeros((), jnp.int32)
     cache_out: Dict[str, Any] = {}
-    h = apply_norm(lp["norm1"], x, cfg.norm, gemma)
+    if spec.mixer != MIXER_NONE:
+        x, cache_out = _apply_mixer(lp, x, spec, cfg, rt, mode=mode,
+                                    positions=positions, cache=cache,
+                                    pos=pos, causal=causal)
+
+    if "cross" in lp:  # whisper decoder cross-attention
+        h = apply_norm(lp["norm_cross"], x, cfg.norm, gemma, cfg.rms_eps)
+        if mode == "decode":
+            y, _ = _apply_attn_decode(lp["cross"], h, spec, cfg, rt,
+                                      cache["cross"], pos, cross=True)
+            cache_out["cross"] = cache["cross"]
+        else:
+            y, c = _apply_attn_full(lp["cross"], h, spec, cfg, rt, positions,
+                                    causal=False, kv_x=enc_out,
+                                    collect_cache=(mode == "prefill"))
+            if mode == "prefill":
+                cache_out["cross"] = {k2: v2 for k2, v2 in c.items()}
+        x = x + y
+
+    if spec.mlp != MLP_NONE:
+        h = apply_norm(lp["norm2"], x, cfg.norm, gemma, cfg.rms_eps)
+        if spec.mlp == MLP_MOE:
+            if rt.moe_fn is not None:
+                y, a = rt.moe_fn(lp["mlp"], h, cfg)
+            else:
+                y, a, held = moe_mod.apply_moe_gshard(lp["mlp"], h, cfg)
+            aux = aux + a
+        else:
+            y = apply_mlp(lp["mlp"], h, spec.mlp)
+        if spec.dense_residual:
+            y = y + apply_mlp(lp["dense_mlp"], h, "swiglu")
+        if cfg.post_norms:
+            y = apply_norm(lp["post_norm2"], y, cfg.norm, gemma, cfg.rms_eps)
+        x = x + y
+        x = rt.constrain(x, "resid")
+    return x, (cache_out or None), aux, held
+
+
+def _apply_mixer(lp: Params, x: jax.Array, spec: LayerSpec,
+                 cfg: ModelConfig, rt: ModelRuntime, *, mode: str,
+                 positions, cache, pos, causal: bool):
+    """The pre-normed mixer and its residual. Returns (x, cache_out)."""
+    gemma = cfg.norm == "rmsnorm" and cfg.post_norms
+    cache_out: Dict[str, Any] = {}
+    h = apply_norm(lp["norm1"], x, cfg.norm, gemma, cfg.rms_eps)
 
     if spec.mixer in (ATTN_GLOBAL, ATTN_LOCAL):
         if mode == "decode":
@@ -282,41 +332,10 @@ def apply_layer(lp: Params, x: jax.Array, spec: LayerSpec, cfg: ModelConfig,
         raise ValueError(spec.mixer)
 
     if cfg.post_norms:
-        y = apply_norm(lp["post_norm1"], y, cfg.norm, gemma)
+        y = apply_norm(lp["post_norm1"], y, cfg.norm, gemma, cfg.rms_eps)
     x = x + y
     x = rt.constrain(x, "resid")
-
-    if "cross" in lp:  # whisper decoder cross-attention
-        h = apply_norm(lp["norm_cross"], x, cfg.norm, gemma)
-        if mode == "decode":
-            y, _ = _apply_attn_decode(lp["cross"], h, spec, cfg, rt,
-                                      cache["cross"], pos, cross=True)
-            cache_out["cross"] = cache["cross"]
-        else:
-            y, c = _apply_attn_full(lp["cross"], h, spec, cfg, rt, positions,
-                                    causal=False, kv_x=enc_out,
-                                    collect_cache=(mode == "prefill"))
-            if mode == "prefill":
-                cache_out["cross"] = {k2: v2 for k2, v2 in c.items()}
-        x = x + y
-
-    if spec.mlp != MLP_NONE:
-        h = apply_norm(lp["norm2"], x, cfg.norm, gemma)
-        if spec.mlp == MLP_MOE:
-            if rt.moe_fn is not None:
-                y, a = rt.moe_fn(lp["mlp"], h, cfg)
-            else:
-                y, a = moe_mod.apply_moe_gshard(lp["mlp"], h, cfg)
-            aux = aux + a
-        else:
-            y = apply_mlp(lp["mlp"], h, spec.mlp)
-        if spec.dense_residual:
-            y = y + apply_mlp(lp["dense_mlp"], h, "swiglu")
-        if cfg.post_norms:
-            y = apply_norm(lp["post_norm2"], y, cfg.norm, gemma)
-        x = x + y
-        x = rt.constrain(x, "resid")
-    return x, (cache_out or None), aux
+    return x, cache_out
 
 
 # ---------------------------------------------------------------------------
@@ -327,20 +346,24 @@ def _run_groups(params: Params, cfg: ModelConfig, rt: ModelRuntime,
                 x: jax.Array, *, mode: str, positions, enc_out=None,
                 cache=None, pos=None, groups=None, prefix: str = "group",
                 causal: bool = True):
-    """Scan over each (period, repeats) group. Returns (x, caches, aux)."""
+    """Scan over each (period, repeats) group. Returns (x, caches, aux,
+    held). A layer with no cache (no mixer) has no entry in the cache
+    tree, nor a group of such layers."""
     groups = groups if groups is not None else cfg.groups
     total_aux = jnp.zeros((), jnp.float32)
+    total_held = jnp.zeros((), jnp.int32)
     caches_out = {}
     for gi, (period, reps) in enumerate(groups):
         gp = params[f"{prefix}{gi}"]
-        gcache = cache[f"{prefix}{gi}"] if cache is not None else None
+        gcache = cache.get(f"{prefix}{gi}") if cache is not None else None
 
         def body(carry, xs, period=period):
-            xc, aux_c = carry
+            xc, aux_c, held_c = carry
             lp_all, cache_slice = xs
             new_cache_slice = {}
             for i, spec in enumerate(period):
-                c_i = None if cache_slice is None else cache_slice[f"p{i}"]
+                c_i = None if cache_slice is None else \
+                    cache_slice.get(f"p{i}")
                 base = functools.partial(
                     apply_layer, spec=spec, cfg=cfg, rt=rt, mode=mode,
                     positions=positions, enc_out=enc_out, pos=pos,
@@ -351,17 +374,18 @@ def _run_groups(params: Params, cfg: ModelConfig, rt: ModelRuntime,
                         jax.checkpoint_policies \
                         .dots_with_no_batch_dims_saveable
                     call = jax.checkpoint(call, policy=pol)
-                xc, c_out, a = call(lp_all[f"p{i}"], xc, c_i)
-                new_cache_slice[f"p{i}"] = c_out
+                xc, c_out, a, n_held = call(lp_all[f"p{i}"], xc, c_i)
+                if c_out is not None:
+                    new_cache_slice[f"p{i}"] = c_out
                 aux_c = aux_c + a
-            ys = new_cache_slice if any(
-                v is not None for v in new_cache_slice.values()) else None
-            return (xc, aux_c), ys
+                held_c = held_c + n_held
+            return (xc, aux_c, held_c), (new_cache_slice or None)
 
-        (x, total_aux), ys = jax.lax.scan(body, (x, total_aux), (gp, gcache))
+        (x, total_aux, total_held), ys = jax.lax.scan(
+            body, (x, total_aux, total_held), (gp, gcache))
         if ys is not None:
             caches_out[f"{prefix}{gi}"] = ys
-    return x, (caches_out or None), total_aux
+    return x, (caches_out or None), total_aux, total_held
 
 
 def encode(params: Params, cfg: ModelConfig, rt: ModelRuntime,
@@ -373,11 +397,12 @@ def encode(params: Params, cfg: ModelConfig, rt: ModelRuntime,
     if "pos_embed" in enc:
         x = x + enc["pos_embed"][:s][None].astype(x.dtype)
     positions = jnp.arange(s)
-    x, _, _ = _run_groups(enc, cfg, rt, x, mode="full", positions=positions,
-                          groups=((cfg.pattern[:1], cfg.n_enc_layers),),
-                          causal=False)
+    x, _, _, _ = _run_groups(enc, cfg, rt, x, mode="full",
+                             positions=positions,
+                             groups=((cfg.pattern[:1], cfg.n_enc_layers),),
+                             causal=False)
     gemma = cfg.norm == "rmsnorm" and cfg.post_norms
-    return apply_norm(enc["final_norm"], x, cfg.norm, gemma)
+    return apply_norm(enc["final_norm"], x, cfg.norm, gemma, cfg.rms_eps)
 
 
 def forward(params: Params, cfg: ModelConfig, rt: ModelRuntime,
@@ -396,10 +421,10 @@ def forward(params: Params, cfg: ModelConfig, rt: ModelRuntime,
     if cfg.enc_dec:
         assert enc_frames is not None
         enc_out = encode(params, cfg, rt, enc_frames)
-    x, caches, aux = _run_groups(params, cfg, rt, x, mode=mode,
-                                 positions=positions, enc_out=enc_out)
+    x, caches, aux, _ = _run_groups(params, cfg, rt, x, mode=mode,
+                                    positions=positions, enc_out=enc_out)
     gemma = cfg.norm == "rmsnorm" and cfg.post_norms
-    x = apply_norm(params["final_norm"], x, cfg.norm, gemma)
+    x = apply_norm(params["final_norm"], x, cfg.norm, gemma, cfg.rms_eps)
     return x, caches, aux
 
 
@@ -461,29 +486,35 @@ def init_cache(cfg: ModelConfig, rt: ModelRuntime, batch: int,
         g, gs = {}, {}
         for i, spec in enumerate(period):
             c1, s1 = layer_cache(spec)
+            if not c1:  # no mixer: no state
+                continue
             g[f"p{i}"] = jax.tree.map(
                 lambda a: jnp.broadcast_to(a, (reps,) + a.shape), c1)
             gs[f"p{i}"] = jax.tree.map(
                 lambda s: (None,) + tuple(s), s1,
                 is_leaf=lambda t: isinstance(t, tuple))
-        cache[f"group{gi}"] = g
-        specs[f"group{gi}"] = gs
+        if g:
+            cache[f"group{gi}"] = g
+            specs[f"group{gi}"] = gs
     return cache, specs
 
 
 def decode_step(params: Params, cfg: ModelConfig, rt: ModelRuntime,
                 cache: Params, tokens: jax.Array, pos: jax.Array):
     """One token: tokens [B] int32, pos scalar int32.
-    Returns (logits [B, V], new_cache)."""
+    Returns (logits [B, V], new_cache, held): ``held`` is how many of this
+    step's router picks land on the experts held here, summed over the
+    expert layers (an int32 scalar, 0 for a model with no experts)."""
     x = embed_tokens(params, tokens)[:, None]  # [B,1,D]
     if "pos_embed" in params:
         x = x + jax.lax.dynamic_slice_in_dim(
             params["pos_embed"], pos, 1, axis=0)[None].astype(x.dtype)
-    x, new_cache, _ = _run_groups(params, cfg, rt, x, mode="decode",
-                                  positions=pos[None], cache=cache, pos=pos)
+    x, new_cache, _, held = _run_groups(params, cfg, rt, x, mode="decode",
+                                        positions=pos[None], cache=cache,
+                                        pos=pos)
     gemma = cfg.norm == "rmsnorm" and cfg.post_norms
-    x = apply_norm(params["final_norm"], x, cfg.norm, gemma)
-    return lm_head(params, cfg, x[:, 0]), new_cache
+    x = apply_norm(params["final_norm"], x, cfg.norm, gemma, cfg.rms_eps)
+    return lm_head(params, cfg, x[:, 0]), new_cache, held
 
 
 def prefill(params: Params, cfg: ModelConfig, rt: ModelRuntime,

@@ -92,8 +92,17 @@ class ServeEngine:
         # host copies of spills that failed after ``cache`` was freed
         # (see SpillTicket): {session name: state dict}
         self.failed_spills: Dict[str, dict] = {}
-        self._decode = jax.jit(
-            lambda p, c, t, pos: tfm.decode_step(p, cfg, rt, c, t, pos))
+        # router picks per decoded token (all expert layers, one row;
+        # ``cfg`` is None for a test's stand-in step)
+        moe = getattr(cfg, "moe", None)
+        self._picks = moe.top_k * cfg.n_moe_layers if moe else 0
+
+        def step(p, c, t, pos, held):
+            logits, c, n = tfm.decode_step(p, cfg, rt, c, t, pos)
+            return logits, c, held + n
+        # (params, cache, tokens, pos, running count of the picks held
+        # here) -> (logits, cache, that count with this step's added)
+        self._decode = jax.jit(step)
         self._prefill = jax.jit(
             functools.partial(tfm.prefill, cfg=cfg, rt=rt))
 
@@ -114,24 +123,37 @@ class ServeEngine:
         (argument prep and the jitted step's dispatch), ``.sample``
         (argmax dispatch) and ``.sync`` (the wait for the token on the
         host); the call adds its tokens and syncs to the counters
-        ``serve.decode.tokens`` and ``serve.decode.host_syncs``."""
+        ``serve.decode.tokens`` and ``serve.decode.host_syncs``. A model
+        with expert layers also adds the router's top-k picks to
+        ``serve.moe.picks``, and those that land on the experts held
+        here to ``serve.moe.picks_held``: the jitted step carries that
+        count, which comes to the host at the last token's sync."""
         out = [np.asarray(first_tokens, np.int32)]
         toks = jnp.asarray(out[0])
         syncs = 0
-        for _ in range(steps):
+        held = np.int32(0)
+        for i in range(steps):
             with annotate("engine.decode.step"):
-                logits, self.cache = self._decode(
-                    self.params, self.cache, toks, jnp.int32(self.pos))
+                logits, self.cache, held = self._decode(
+                    self.params, self.cache, toks, jnp.int32(self.pos), held)
             with annotate("engine.decode.sample"):
                 toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             self.pos += 1
             with annotate("engine.decode.sync"):
-                out.append(np.asarray(toks))
+                if i == steps - 1:
+                    tok, held = jax.device_get((toks, held))
+                    out.append(tok)
+                else:
+                    out.append(np.asarray(toks))
             syncs += 1
         obs = self._obs()
         if obs is not None:
             obs.counter("serve.decode.tokens").inc(steps)
             obs.counter("serve.decode.host_syncs").inc(syncs)
+            if self._picks:
+                obs.counter("serve.moe.picks").inc(
+                    steps * self._picks * len(out[0]))
+                obs.counter("serve.moe.picks_held").inc(int(held))
         return np.stack(out, axis=1)
 
     # ---- session-state handoff (the SessionManager's interface) ----

@@ -39,7 +39,9 @@ Dataset* in the existing exchange catalog:
     in-DRAM session table — zero object-store probes, lint-enforced;
   * every lifecycle edge is instrumented through the TelemetryPlane:
     ``serve.sessions_active`` gauge, the ``serve.spill_to_ack_s``
-    histogram, the ``serve.resume`` / ``serve.spill`` spans (their
+    histogram, the bytes each suspend releases by kind of state
+    (``serve.session.state_bytes.kv`` / ``.recurrent``), the
+    ``serve.resume`` / ``serve.spill`` spans (their
     ``span.<name>.s`` histograms), and ONE trace-span tree per
     session lifetime (the root span's trace id is persisted in the
     record's annotations, so the tree reconnects across processes).
@@ -54,6 +56,7 @@ from __future__ import annotations
 import threading
 import time
 import zlib
+from concurrent.futures import Future
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence
 
@@ -63,6 +66,27 @@ from repro.core.dataset_exchange import (DEFAULT_LEASE_TTL_S,
 from repro.obs.metrics import Registry
 
 WORKFLOW = "serve"
+
+
+# a session state's cache leaves by kind: attention keys, values and their
+# positions; everything else (SSM / RG-LRU state, conv carries) is recurrent
+KV_LEAVES = ("k", "v", "kpos")
+STATE_KINDS = ("kv", "recurrent")
+
+
+def state_bytes(cache) -> Dict[str, int]:
+    """Bytes of a session's cache tree by kind (``kv``, ``recurrent``)."""
+    out = dict.fromkeys(STATE_KINDS, 0)
+
+    def walk(node, leaf: str) -> None:
+        if isinstance(node, dict):
+            for k, v in node.items():
+                walk(v, k)
+        elif node is not None:
+            out["kv" if leaf in KV_LEAVES else "recurrent"] += \
+                int(node.nbytes)
+    walk(cache, "")
+    return out
 
 
 def session_dataset(name: str) -> str:
@@ -113,6 +137,9 @@ class SessionManager:
         self._c_resumes = reg.counter("serve.resumes")
         self._c_evictions = reg.counter("serve.evictions")
         self._c_adoptions = reg.counter("serve.adoptions")
+        self._c_state_bytes = {
+            kind: reg.counter(f"serve.session.state_bytes.{kind}")
+            for kind in STATE_KINDS}
         self._lock = threading.Lock()
         self._sessions: Dict[str, _Session] = {}
 
@@ -296,6 +323,8 @@ class SessionManager:
             with self._lock:
                 sess.engine = None
             self._g_active.dec()
+            for kind, n in state_bytes(state.get("cache")).items():
+                self._c_state_bytes[kind].inc(n)
         # a waited spill ends on this thread; an async one on the I/O
         # thread, so only the waited one is a profiler span
         sp = self._begin("serve.spill", sess, session=name,
@@ -312,10 +341,25 @@ class SessionManager:
                 raise
             self._end(sp, version=rec["version"])
             return rec
-        fut = self.tiered.run_async(
-            lambda: self._publish_spill(name, state, t0))
+        # the flag is set before the submit: a publish that ends before
+        # run_async returns would otherwise leave a done future in it
+        fut: Future = Future()
         with self._lock:
             sess.spilling = fut
+
+        def publish() -> None:
+            try:
+                fut.set_result(self._publish_spill(name, state, t0))
+            except Exception as e:  # noqa: BLE001 — handed to the future
+                fut.set_exception(e)
+        try:
+            self.tiered.run_async(publish)
+        except Exception:
+            with self._lock:
+                sess.spilling = None
+                sess.pending_state = state
+            self._end(sp, status="error")
+            raise
 
         def _done(f) -> None:
             if f.exception() is not None:

@@ -16,11 +16,13 @@ EXPECT = {
     "arctic-480b": 480e9,
     "mamba2-1.3b": 1.3e9,
     "internvl2-26b": 26e9,
+    "nemotron3-nano-30b-a3b": 31.6e9,
+    "nemotron3-nano-30b-a3b-ep16": 4.04e9,   # 8 of 128 experts held
 }
 
 
 def test_ten_archs():
-    assert len(ARCH_IDS) == 10
+    assert len(ARCH_IDS) == 12
 
 
 @pytest.mark.parametrize("arch", ARCH_IDS)
@@ -41,14 +43,14 @@ def test_param_count_close(arch):
 
 def test_cell_matrix_is_40():
     all_cells = list(cells())
-    assert len(all_cells) == 40
+    assert len(all_cells) == 48
     skipped = [c for c in all_cells if c.skip]
     runnable = [c for c in all_cells if not c.skip]
     # long_500k runs only for the sub-quadratic archs
     long_runs = [c for c in runnable if c.shape.name == "long_500k"]
     assert sorted(c.arch for c in long_runs) == ["mamba2-1.3b",
                                                  "recurrentgemma-9b"]
-    assert len(skipped) == 8
+    assert len(skipped) == 10
     for c in skipped:
         assert c.shape.name == "long_500k"
 

@@ -78,7 +78,7 @@ def test_moe_parallel_matches_gshard(n_experts):
     init_moe(pb, cfg, make_moe_layout(cfg, 4))
     params = {{k: v.astype(jnp.float32) for k, v in pb.params.items()}}
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, 32)) * 0.5
-    y_ref, _ = apply_moe_gshard(params, x, cfg)
+    y_ref, _, _ = apply_moe_gshard(params, x, cfg)
     etp = make_moe_etp(mesh)
     y1, _ = jax.jit(lambda p, xx: etp(p, xx, cfg))(params, x)
     rep = make_moe_replicated(mesh)
@@ -110,7 +110,7 @@ def test_moe_decode_2d_experts(n_experts):
     init_moe(pb, cfg, make_moe_layout(cfg, 4))
     params = {{k: v.astype(jnp.float32) for k, v in pb.params.items()}}
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 1, 32)) * 0.5
-    y_ref, _ = apply_moe_gshard(params, x, cfg)
+    y_ref, _, _ = apply_moe_gshard(params, x, cfg)
     rep2d = make_moe_replicated(mesh, expert_2d=True)
     y, _ = jax.jit(lambda p, xx: rep2d(p, xx, cfg))(params, x)
     assert float(jnp.max(jnp.abs(y - y_ref))) < 1e-4

@@ -36,8 +36,8 @@ def test_forward_prefill_decode_consistent(arch):
     full_logits = T.lm_head(params, cfg, hidden)
     assert bool(jnp.isfinite(full_logits).all())
     logits_pre, cache = T.prefill(params, cfg, rt, tokens[:, :-1], **kw)
-    logits_dec, _ = T.decode_step(params, cfg, rt, cache, tokens[:, -1],
-                                  jnp.int32(S - 1))
+    logits_dec, _, _ = T.decode_step(params, cfg, rt, cache, tokens[:, -1],
+                                     jnp.int32(S - 1))
     assert float(jnp.max(jnp.abs(logits_pre - full_logits[:, -2]))) < 0.05
     assert float(jnp.max(jnp.abs(logits_dec - full_logits[:, -1]))) < 0.05
 

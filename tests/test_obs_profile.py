@@ -84,8 +84,9 @@ def _engine(cluster):
     from repro.serve.engine import ServeEngine
     eng = ServeEngine(None, None, {}, tiered=cluster.tiered)
     eng._decode = jax.jit(
-        lambda p, c, t, pos: (jnp.zeros((t.shape[0], 8)).at[:, 3].set(pos),
-                              {"k": c["k"] + 1.0}))
+        lambda p, c, t, pos, held: (
+            jnp.zeros((t.shape[0], 8)).at[:, 3].set(pos),
+            {"k": c["k"] + 1.0}, held))
     eng.cache, eng.pos = {"k": jnp.zeros(4)}, 0
     return eng
 
