@@ -99,10 +99,14 @@ class ServeEngine:
 
         def step(p, c, t, pos, held):
             logits, c, n = tfm.decode_step(p, cfg, rt, c, t, pos)
-            return logits, c, held + n
+            return (jnp.argmax(logits, axis=-1).astype(jnp.int32), c,
+                    pos + 1, held + n)
         # (params, cache, tokens, pos, running count of the picks held
-        # here) -> (logits, cache, that count with this step's added)
-        self._decode = jax.jit(step)
+        # here) -> (greedy next tokens, cache, pos + 1, that count with
+        # this step's added). The cache, pos and count are donated, so
+        # steps queued ahead of the chip reuse one cache's buffers rather
+        # than each allocating its own.
+        self._decode = jax.jit(step, donate_argnums=(1, 3, 4))
         self._prefill = jax.jit(
             functools.partial(tfm.prefill, cfg=cfg, rt=rt))
 
@@ -118,49 +122,48 @@ class ServeEngine:
         return np.asarray(jnp.argmax(logits, axis=-1), np.int32)
 
     def decode(self, first_tokens: np.ndarray, steps: int) -> np.ndarray:
-        """``steps`` greedy tokens after ``first_tokens``. Each token is
-        three profiler spans on the caller's thread: ``engine.decode.step``
-        (argument prep and the jitted step's dispatch), ``.sample``
-        (argmax dispatch) and ``.sync`` (the wait for the token on the
-        host); the call adds its tokens and syncs to the counters
-        ``serve.decode.tokens`` and ``serve.decode.host_syncs``. A model
-        with expert layers also adds the router's top-k picks to
-        ``serve.moe.picks``, and those that land on the experts held
-        here to ``serve.moe.picks_held``: the jitted step carries that
-        count, which comes to the host at the last token's sync."""
-        out = [np.asarray(first_tokens, np.int32)]
-        toks = jnp.asarray(out[0])
-        syncs = 0
-        held = np.int32(0)
-        for i in range(steps):
+        """``steps`` greedy tokens after ``first_tokens``, as ``[B, 1 +
+        steps]``. The greedy chain stays on the device: each jitted step
+        takes its own argmax and carries the position, so the loop only
+        dispatches, and the host reads the call's tokens once, at its end.
+        Each token is one profiler span on the caller's thread,
+        ``engine.decode.step`` (the step's dispatch); the call's one wait
+        for its tokens is ``.sync``. The call adds its tokens and its one
+        sync to the counters ``serve.decode.tokens`` and
+        ``serve.decode.host_syncs``. A model with expert layers also adds
+        the router's top-k picks to ``serve.moe.picks``, and those that
+        land on the experts held here to ``serve.moe.picks_held``: the
+        jitted step carries that count, which comes to the host with the
+        tokens."""
+        first = np.asarray(first_tokens, np.int32)
+        toks = [jnp.asarray(first)]
+        pos = jnp.asarray(self.pos, jnp.int32)
+        held = jnp.zeros((), jnp.int32)
+        for _ in range(steps):
             with annotate("engine.decode.step"):
-                logits, self.cache, held = self._decode(
-                    self.params, self.cache, toks, jnp.int32(self.pos), held)
-            with annotate("engine.decode.sample"):
-                toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            self.pos += 1
-            with annotate("engine.decode.sync"):
-                if i == steps - 1:
-                    tok, held = jax.device_get((toks, held))
-                    out.append(tok)
-                else:
-                    out.append(np.asarray(toks))
-            syncs += 1
+                tok, self.cache, pos, held = self._decode(
+                    self.params, self.cache, toks[-1], pos, held)
+            toks.append(tok)
+        self.pos += steps
+        with annotate("engine.decode.sync"):
+            out, held = jax.device_get((toks[1:], held))
         obs = self._obs()
         if obs is not None:
             obs.counter("serve.decode.tokens").inc(steps)
-            obs.counter("serve.decode.host_syncs").inc(syncs)
+            obs.counter("serve.decode.host_syncs").inc(1)
             if self._picks:
                 obs.counter("serve.moe.picks").inc(
-                    steps * self._picks * len(out[0]))
+                    steps * self._picks * len(first))
                 obs.counter("serve.moe.picks_held").inc(int(held))
-        return np.stack(out, axis=1)
+        return np.stack([first, *out], axis=1)
 
     # ---- session-state handoff (the SessionManager's interface) ----
     def export_state(self, release: bool = False) -> dict:
         """Host copy of the session state (``{"cache", "pos"}``) —
         what the manager publishes as a dataset version. ``release``
-        frees the engine's DRAM copy after the export."""
+        frees the engine's DRAM copy after the export. The next decode
+        donates the device cache; the host copy keeps its bytes (on a
+        CPU backend it is a view, and JAX then declines the donation)."""
         assert self.cache is not None, "no session state resident"
         host = jax.tree.map(np.asarray, self.cache)
         obj = {"cache": host, "pos": np.int32(self.pos)}

@@ -191,7 +191,7 @@ def test_decode_counts_the_picks_and_suspend_the_state_bytes(cluster):
     reg = cluster.tiered.obs.registry
     assert reg.counter("serve.moe.picks").value == 7 * 4 * 3
     assert reg.counter("serve.moe.picks_held").value == 7 * 4
-    assert reg.counter("serve.decode.host_syncs").value == 7
+    assert reg.counter("serve.decode.host_syncs").value == 2
     kinds = state_bytes(jax.tree.map(np.asarray, eng.cache))
     kv = 2 * 32 * 2 * 16 * 2 * 2 + 2 * 32 * 4     # 2 attention layers
     assert kinds["kv"] == kv and kinds["recurrent"] > 0
